@@ -21,6 +21,10 @@ from repro.core import (  # noqa: E402
     next_id,
     reset_ids,
 )
+from repro.core.jax_backend import configure_compile_cache  # noqa: E402
+
+# benches that touch the jax backend share one persistent compile cache
+configure_compile_cache()
 
 #: Every ``emit`` row of the current process, for machine-readable output
 #: (``BENCH_daemons.json``; see ``write_bench_json``).

@@ -45,6 +45,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from . import jax_backend
 from .store import JobStore
 from .types import (
     App,
@@ -202,9 +203,9 @@ class BatchValidationEngine:
         self.store = store
         # "jax": homogeneous float tensor payload batches of fuzzy
         # comparators route through the kernels/quorum_compare Pallas
-        # kernel (interpret mode on CPU); scalars/mixed payloads and every
-        # other comparator keep the pure-NumPy digest path
-        self.backend = backend
+        # kernel; scalars/mixed payloads and every other comparator keep
+        # the pure-NumPy digest path
+        self.backend = jax_backend.resolve_backend(backend)
         self._digest_fns: Dict[str, Any] = {}
 
     def digest_fn(self, app: App):
@@ -215,10 +216,7 @@ class BatchValidationEngine:
             if fn is not None and self.backend == "jax":
                 params = getattr(app.comparator, "fuzzy_params", None)
                 if params is not None:
-                    from .jax_backend import HAVE_JAX, fuzzy_digest_jax
-
-                    if HAVE_JAX:
-                        fn = fuzzy_digest_jax(fn, *params)
+                    fn = jax_backend.fuzzy_digest_jax(fn, *params)
             self._digest_fns[app.name] = fn
         return fn
 

@@ -10,31 +10,29 @@ NumPy/Python code. The contract is the repo's standing one, extended one
 level: scalar oracle ⇒ NumPy engine ⇒ JAX engine, *bit-identical* —
 asserted whole-run by the 4th parity axis in ``core/scenarios.run_parity``.
 
-Bit-identity on XLA:CPU is not free. XLA's CPU emitter lets LLVM contract
-``mul`` feeding ``add``/``sub`` inside one fusion into an FMA (the product
-is never rounded), which breaks last-bit identity with NumPy f64 — and in
-jax 0.4.x no flag (``--xla_allow_excess_precision=false``,
-``--xla_cpu_enable_fast_math=false``, ``--xla_backend_optimization_level=0``)
-or ``lax.optimization_barrier`` blocks it: barriers are elided before the
-fusion is emitted. What *does* hold bit-identical inside a single jit
-(probed empirically, pinned by ``tests/test_jax_backend.py``):
+The bit-identity is asserted on XLA:CPU, where XLA's CPU emitter may let
+LLVM contract a ``mul`` feeding an ``add``/``sub`` inside one fusion into
+an FMA (the product is never rounded). The installed JAX (0.9.0) is not
+relied on to block that with a flag or ``lax.optimization_barrier``;
+instead every kernel here is **staged**: multiplies that feed
+accumulations run in their own jit (the dispatch boundary materializes
+the rounded product), and the adds run in a second jit. What the CPU
+parity tests (``tests/test_jax_backend.py``, the scenario matrix) pin
+inside a single jit is: elementwise mul, div, sub, compares,
+``where``/min/max, boolean logic, gathers/scatters; add/sub chains whose
+operands are materialized (row folds, ``fori_loop`` carries); and mul by
+an exact power of two feeding an add.
 
-  * elementwise mul, div, sub, compares, ``where``/min/max, boolean logic,
-    gathers/scatters;
-  * add/sub chains whose operands are **not** un-materialized products
-    (sequential row folds, ``fori_loop`` accumulator carries);
-  * mul by an exactly-representable power of two feeding an add (the
-    product is exact, so contraction cannot change the result).
-
-So every kernel here is **staged**: multiplies that feed accumulations run
-in their own jit (the dispatch boundary materializes the rounded product),
-and the adds run in a second jit. See the per-field tolerance table in
-``docs/ARCHITECTURE.md`` ("execution backends") — with the staging in
-place every mirrored field is in the "bit-identical" row; f32 rows apply
-only to the Pallas ``quorum_compare`` digest path, which casts payloads to
-f32 by design (kernel contract) and is therefore gated to payloads whose
-agreement/disagreement is far from the tolerance boundary (the digest
-contract ``core/validator.py`` already documents).
+On a TPU v5e the kernels compile as they are, but the chip has no native
+f64: XLA emulates it, and the results are not IEEE f64. On the chip the
+mirrored floats differ from NumPy's in the last bits (up to ~6e-14
+relative), while every decision mask matches; ``chip_smoke.py`` measures
+this per field. A host decision taken on such a float therefore needs a
+margin, never an exact tie (``world.COMPLETION_TOL``). See the tolerance
+table in ``docs/ARCHITECTURE.md`` ("execution backends"). The Pallas ``quorum_compare`` digest path
+compares in f32 by design (kernel contract) and is therefore gated to
+payloads whose agreement/disagreement is far from the tolerance boundary
+(the digest contract ``core/validator.py`` documents).
 
 Shapes are padded to power-of-two buckets so jit retraces stay O(log n)
 per call site. Padding lanes are neutralized (masks forced False, scatter
@@ -42,8 +40,9 @@ indices out of range with ``mode="drop"``), never observable.
 """
 from __future__ import annotations
 
-import warnings
+import os
 from functools import partial
+from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -64,11 +63,24 @@ except Exception:  # pragma: no cover
 
 BACKENDS = ("numpy", "jax")
 
-# CPU XLA may decline buffer donation; the fallback copy is correct, the
-# warning is noise at one-per-jit-call volume.
-warnings.filterwarnings(
-    "ignore", message="Some donated buffers were not usable"
-)
+_REPO_ROOT = Path(__file__).resolve().parents[3]
+
+
+def configure_compile_cache() -> Optional[str]:
+    """Turn on JAX's persistent compilation cache for a chip run.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no
+    path is set here; otherwise the cache lives at the fixed
+    ``<repo>/.jax_cache`` (git-ignored; the path is part of the cache key,
+    so it never moves). The staged jits each compile well under JAX's
+    default one-second threshold, so the threshold is dropped to keep
+    them. Returns the cache directory in use."""
+    if not HAVE_JAX:
+        return None
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(_REPO_ROOT / ".jax_cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return jax.config.jax_compilation_cache_dir
 
 
 def resolve_backend(backend: str) -> str:
@@ -281,7 +293,7 @@ if HAVE_JAX:
         tot = q_total[:, idx]
         Q = m.shape[0]
         rowmask = jnp.arange(Q)[:, None] < counts[None, :]
-        return m & (run >= tot - 1e-6) & rowmask
+        return m & (run >= tot - 1e-6) & rowmask  # world.COMPLETION_TOL
 
     @jax.jit
     def _k_col_upload(dev, host_vals, cols):
@@ -306,7 +318,17 @@ def dispatch_elig(valid: np.ndarray, target: np.ndarray, start: int,
 
 def dispatch_group_mask(g_ok_inv: np.ndarray, hr_rep: np.ndarray,
                         host_hr_rep: np.ndarray, kok: np.ndarray) -> np.ndarray:
-    return np.asarray(_k_group_mask(g_ok_inv, hr_rep, host_hr_rep, kok))
+    M = len(g_ok_inv)
+    P = _bucket(M)
+
+    def pad(a):
+        out = np.zeros(P, dtype=a.dtype)  # pad lanes: g_ok False → masked
+        out[:M] = a
+        return out
+
+    return np.asarray(
+        _k_group_mask(pad(g_ok_inv), pad(hr_rep), pad(host_hr_rep), pad(kok))
+    )[:M]
 
 
 def dispatch_scores(
@@ -521,7 +543,12 @@ class WorldDeviceMirror:
             return
         if not self.dirty:
             return
-        cols = np.fromiter(sorted(self.dirty), np.int64, len(self.dirty))
+        # bucketed column count; pad lanes repeat the last dirty column, so
+        # they rewrite it with its own (identical) values
+        n_dirty = len(self.dirty)
+        cols = np.empty(_bucket(n_dirty), dtype=np.int64)
+        cols[:n_dirty] = sorted(self.dirty)
+        cols[n_dirty:] = cols[n_dirty - 1]
         cj = jnp.asarray(cols)
         self.q_total = _k_col_upload(self.q_total, world.q_total[:, cols], cj)
         self.q_runtime = _k_col_upload(self.q_runtime, world.q_runtime[:, cols], cj)
@@ -594,10 +621,10 @@ class WorldDeviceMirror:
 # ----------------------------------------------------------------------
 
 
-def quorum_group_codes(mat: np.ndarray, rtol: float, atol: float,
-                       interpret: bool = True) -> np.ndarray:
+def quorum_group_codes(mat: np.ndarray, rtol: float, atol: float) -> np.ndarray:
     """Group codes for a homogeneous (n, d) float payload matrix via the
-    ``kernels/quorum_compare`` Pallas kernel (interpret mode on CPU).
+    ``kernels/quorum_compare`` Pallas kernel (compiled on the chip,
+    interpret mode on the CPU backend only).
 
     Greedy first-match grouping: row i joins the first group whose
     representative it agrees with (kernel verdict ``n_bad == 0`` under the
@@ -622,9 +649,7 @@ def quorum_group_codes(mat: np.ndarray, rtol: float, atol: float,
             continue
         assigned = False
         for g, r in enumerate(reps):
-            n_bad, _ = quorum_compare(
-                mat[i], mat[r], rtol=rtol, atol=atol, interpret=interpret
-            )
+            n_bad, _ = quorum_compare(mat[i], mat[r], rtol=rtol, atol=atol)
             if int(n_bad) == 0:
                 codes[i] = g
                 assigned = True

@@ -62,7 +62,7 @@ from .types import (
     ResourceType,
     ValidateState,
 )
-from .world import HostArrays
+from .world import COMPLETION_TOL, HostArrays
 
 # ---------------------------------------------------------------------------
 # Host population model (EmBOINC's "random model")
@@ -628,7 +628,12 @@ class GridSimulation:
         q_total = world.q_total
         q_runtime = world.q_runtime
         for row in world.running_rows(host_id):
-            remaining = max(0.0, float(q_total[row, i] - q_runtime[row, i]))
+            remaining = float(q_total[row, i] - q_runtime[row, i])
+            # a job the completion predicate already counts as done completes
+            # now: a last-bit difference in accrued runtime (the TPU's f64 is
+            # not IEEE) must not push its event into the next epoch
+            if remaining <= COMPLETION_TOL:
+                remaining = 0.0
             self._push(t + remaining, _COMPLETE, host_id, gen)
 
     def _mark_completions(

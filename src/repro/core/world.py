@@ -65,6 +65,10 @@ if TYPE_CHECKING:  # pragma: no cover
 _RUNNING = RunState.RUNNING
 _DONE = RunState.DONE
 
+#: A running job within this many seconds of its total cost has completed
+#: (the completion predicate; ``jax_backend._k_completed`` uses the same).
+COMPLETION_TOL = 1e-6
+
 
 class ExpDrawCache:
     """FIFO uniform-draw cache reproducing ``random.Random.expovariate``.
@@ -610,8 +614,25 @@ class HostArrays:
         col = slice(0, cnt)
         return np.flatnonzero(
             self.q_running[col, i]
-            & (self.q_runtime[col, i] >= self.q_total[col, i] - 1e-6)
+            & (self.q_runtime[col, i] >= self.q_total[col, i] - COMPLETION_TOL)
         )
+
+    def completed_mask(self, idx: np.ndarray) -> np.ndarray:
+        """[K, len(idx)] completion mask over dense slots ``idx``, K being
+        their deepest queue: running rows that have accrued their full
+        cost, rows past each slot's queue count masked out.
+        Backend-dispatched, like ``_advance_cols``."""
+        counts = self.q_count[idx]
+        K = int(counts.max()) if len(idx) else 0
+        if K == 0:
+            return np.zeros((0, len(idx)), dtype=bool)
+        if self._mirror is not None:
+            return self._mirror.completed_mask(self, idx, counts)[:K]
+        sub = self.q_running[:K, idx] & (
+            self.q_runtime[:K, idx] >= self.q_total[:K, idx] - COMPLETION_TOL
+        )
+        sub &= np.arange(K)[:, None] < counts[None, :]
+        return sub
 
     def completed_rows_batch(
         self, host_ids: Sequence[int]
@@ -623,17 +644,9 @@ class HostArrays:
         if not live:
             return {}
         idx = np.fromiter((i for _, i in live), np.int64, len(live))
-        counts = self.q_count[idx]
-        K = int(counts.max()) if len(idx) else 0
-        if K == 0:
+        sub = self.completed_mask(idx)
+        if sub.shape[0] == 0:
             return {h: np.zeros(0, dtype=np.int64) for h, _ in live}
-        if self._mirror is not None:
-            sub = self._mirror.completed_mask(self, idx, counts)[:K]
-        else:
-            sub = self.q_running[:K, idx] & (
-                self.q_runtime[:K, idx] >= self.q_total[:K, idx] - 1e-6
-            )
-            sub &= np.arange(K)[:, None] < counts[None, :]
         out: Dict[int, np.ndarray] = {}
         rows, cols = np.nonzero(sub.T)  # host-major
         split = np.searchsorted(rows, np.arange(len(idx) + 1))

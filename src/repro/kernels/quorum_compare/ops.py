@@ -4,7 +4,7 @@ runtime's validator uses on gradient/logit replicas."""
 from __future__ import annotations
 
 import functools
-from typing import Any, Tuple
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -21,11 +21,15 @@ def quorum_compare(
     *,
     rtol: float = 1e-5,
     atol: float = 1e-8,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Returns (n_bad, sum_sq_diff) over flattened inputs."""
-    af = a.reshape(-1)
-    bf = b.reshape(-1)
+    """Returns (n_bad, sum_sq_diff) over flattened inputs, compared in f32
+    (the kernel contract). ``interpret=None`` runs the Pallas interpreter
+    on the CPU backend only; on any other backend the kernel is compiled."""
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    af = a.reshape(-1).astype(jnp.float32)
+    bf = b.reshape(-1).astype(jnp.float32)
     n = af.shape[0]
     pad = (-n) % _LANES
     if pad:
@@ -51,7 +55,7 @@ def tree_quorum_agree(
     rtol: float = 1e-4,
     atol: float = 1e-6,
     max_bad_fraction: float = 0.0,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> bool:
     """Pytree-level fuzzy agreement — the validator comparator (§3.4)."""
     la = jax.tree_util.tree_leaves(tree_a)

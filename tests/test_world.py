@@ -32,6 +32,7 @@ from repro.core import (
 )
 from repro.core.client import ClientJob, ClientPrefs, ClientResource, ProjectAttachment, RunState
 from repro.core.types import ResourceType
+from repro.core.world import COMPLETION_TOL
 
 DAY = 86400.0
 
@@ -182,6 +183,22 @@ class TestClampedAccrual:
         # every instance ever dispatched (pre-clamp, availability toggles
         # landing after nominal finish times inflated accrual past this)
         assert m["busy_cpu_seconds"] <= sim._dispatched_actual_total + 1e-6
+
+    def test_completion_within_tolerance_fires_this_epoch(self):
+        """A running job the completion predicate already counts as done
+        (within ``COMPLETION_TOL`` of its total) gets its completion event
+        at the current time: a last-bit difference in accrued runtime, as
+        the TPU's non-IEEE f64 leaves, must not move it to the next epoch."""
+        _, sim = build_sim(True, epoch=60.0)
+        sim.run(3600.0)
+        world = sim.world
+        host = next(h for h in world.index if len(world.running_rows(h)))
+        i, row = world.index[host], world.running_rows(host)[-1]
+        for gap, due in ((COMPLETION_TOL / 2, 3600.0), (2 * COMPLETION_TOL, 3660.0)):
+            world.q_runtime[row, i] = world.q_total[row, i] - gap
+            sim._reschedule_completions(host, 3600.0)
+            last = next(e for e in sim._heap if e[1] == sim._seq)
+            assert last[0] == due, (gap, last)
 
 
 class TestChurnPurge:
