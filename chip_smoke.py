@@ -61,6 +61,7 @@ from repro.core import (  # noqa: E402
 from repro.core import jax_backend  # noqa: E402
 from repro.core.scenarios import _first_divergence  # noqa: E402
 from repro.core.scheduler import ResourceRequest, ScheduleRequest  # noqa: E402
+from repro.core.tracing import CompileCounter  # noqa: E402
 from repro.core.world import HostArrays  # noqa: E402
 from repro.kernels.quorum_compare.ops import quorum_compare  # noqa: E402
 from repro.service import SchedulerService, run_load  # noqa: E402
@@ -77,38 +78,6 @@ class PhaseFailed(AssertionError):
 # ---------------------------------------------------------------------------
 # reporting
 # ---------------------------------------------------------------------------
-
-
-class CompileCounter:
-    """Counts compile requests (a jit's first call for a shape, compiled
-    or loaded from the persistent cache) and persistent-cache hits through
-    ``jax.monitoring`` (listeners cannot be removed, so one instance is
-    registered per process and phases read deltas)."""
-
-    _instance = None
-
-    def __init__(self) -> None:
-        self.compiles = 0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    @classmethod
-    def get(cls) -> "CompileCounter":
-        if cls._instance is None:
-            cls._instance = cls()
-        return cls._instance
-
-    def _on_duration(self, event: str, secs: float, **kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compiles += 1
-
-    def _on_event(self, event: str, **kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def snapshot(self) -> Tuple[int, int]:
-        return self.compiles, self.cache_hits
 
 
 def float_diff(a: np.ndarray, b: np.ndarray) -> Tuple[int, float]:

@@ -47,6 +47,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from . import tracing
+
 try:  # pragma: no cover - exercised only when jax is absent
     import jax
 
@@ -313,7 +315,8 @@ def dispatch_elig(valid: np.ndarray, target: np.ndarray, start: int,
                   host_id: int) -> np.ndarray:
     """Rotated-scan eligibility mask on device; entry j refers to feeder
     position ``(start + j) % n`` (the caller's ``rot`` order)."""
-    return np.asarray(_k_elig(valid, target, start, host_id))
+    with tracing.span("boinc.dispatch.device", kernel="elig"):
+        return np.asarray(_k_elig(valid, target, start, host_id))
 
 
 def dispatch_group_mask(g_ok_inv: np.ndarray, hr_rep: np.ndarray,
@@ -326,9 +329,10 @@ def dispatch_group_mask(g_ok_inv: np.ndarray, hr_rep: np.ndarray,
         out[:M] = a
         return out
 
-    return np.asarray(
-        _k_group_mask(pad(g_ok_inv), pad(hr_rep), pad(host_hr_rep), pad(kok))
-    )[:M]
+    with tracing.span("boinc.dispatch.device", kernel="group_mask"):
+        return np.asarray(
+            _k_group_mask(pad(g_ok_inv), pad(hr_rep), pad(host_hr_rep), pad(kok))
+        )[:M]
 
 
 def dispatch_scores(
@@ -358,20 +362,21 @@ def dispatch_scores(
         return out
 
     has_bal = bal is not None
-    t_kw, t_bal, t_pr, t_sk = _k_score_terms(
-        pad(kvec), pad(bal) if has_bal else np.zeros(P), pad(prio),
-        pad(skips), w_kw, w_bal, w_pr, w_sk,
-    )
-    if has_bal:
-        scores = _k_score_sum4(t_kw, t_bal, t_pr, t_sk)
-    else:
-        scores = _k_score_sum3(t_kw, t_pr, t_sk)
-    est, scaled = _k_est_scaled(pad(flop), pad(pf), avail)
-    return (
-        np.asarray(scores)[:M].copy(),
-        np.asarray(est)[:M].copy(),
-        np.asarray(scaled)[:M].copy(),
-    )
+    with tracing.span("boinc.dispatch.device", kernel="scores"):
+        t_kw, t_bal, t_pr, t_sk = _k_score_terms(
+            pad(kvec), pad(bal) if has_bal else np.zeros(P), pad(prio),
+            pad(skips), w_kw, w_bal, w_pr, w_sk,
+        )
+        if has_bal:
+            scores = _k_score_sum4(t_kw, t_bal, t_pr, t_sk)
+        else:
+            scores = _k_score_sum3(t_kw, t_pr, t_sk)
+        est, scaled = _k_est_scaled(pad(flop), pad(pf), avail)
+        return (
+            np.asarray(scores)[:M].copy(),
+            np.asarray(est)[:M].copy(),
+            np.asarray(scaled)[:M].copy(),
+        )
 
 
 # ----------------------------------------------------------------------
@@ -642,15 +647,24 @@ def quorum_group_codes(mat: np.ndarray, rtol: float, atol: float) -> np.ndarray:
     n = mat.shape[0]
     codes = np.zeros(n, dtype=np.int64)
     reps: List[int] = []
-    nan_rows = np.isnan(mat).any(axis=1)
+    with tracing.span("boinc.validate.stack"):
+        nan_rows = np.isnan(mat).any(axis=1)
     for i in range(n):
         if nan_rows[i]:
             codes[i] = _nan_sentinel()
             continue
         assigned = False
         for g, r in enumerate(reps):
-            n_bad, _ = quorum_compare(mat[i], mat[r], rtol=rtol, atol=atol)
-            if int(n_bad) == 0:
+            with tracing.span("boinc.validate.pair"):
+                # the call's host side: staging both rows for their
+                # implicit upload, and the launch. The transfer and the
+                # kernel are waited for in int(n_bad). An explicit
+                # device_put here cost ~4% of the pass on a v5e, and
+                # waiting on it ~10%.
+                with tracing.span("boinc.validate.upload"):
+                    n_bad, _ = quorum_compare(mat[i], mat[r], rtol=rtol, atol=atol)
+                agree = int(n_bad) == 0
+            if agree:
                 codes[i] = g
                 assigned = True
                 break
@@ -668,7 +682,8 @@ def fuzzy_digest_jax(base, rtol: float, atol: float):
 
     def fn(outputs: Sequence) -> np.ndarray:
         if len(outputs) >= 2 and isinstance(outputs[0], np.ndarray):
-            mat = _homogeneous_arrays(outputs)
+            with tracing.span("boinc.validate.stack"):
+                mat = _homogeneous_arrays(outputs)
             if mat is not None and mat.dtype.kind == "f":
                 return quorum_group_codes(mat, rtol, atol)
         return base(outputs)

@@ -36,6 +36,7 @@ from .estimation import RuntimeEstimator
 from .keywords import KeywordPrefs, keyword_score
 from .shard import ShardMap
 from .store import JobStore
+from .tracing import span
 from .types import (
     App,
     AppVersion,
@@ -189,49 +190,50 @@ class Feeder:
         longer UNSENT) that cannot be refilled are cleared, so between
         fills every resident slot references a dispatchable instance — the
         persistent engine's validity arrays rely on this."""
-        in_cache = {s.instance_id for s in self.slots if s is not None}
-        stale = [i for i, s in enumerate(self.slots) if s is not None and self._stale(s)]
-        vacancies = [i for i, s in enumerate(self.slots) if s is None or self._stale(s)]
-        if not vacancies:
-            return 0
-        per_app: Dict[str, List[JobInstance]] = {}
-        for app_name in self.store.apps:
-            # exclude in-cache ids *inside* the queue walk: with a backlog
-            # larger than the cache, the oldest UNSENT rows are exactly the
-            # cached ones, and filtering after the limit would starve refills
-            per_app[app_name] = self.store.unsent_instances(
-                app_name, limit=len(vacancies), exclude=in_cache
-            )
-        filled = 0
-        app_names = [a for a in per_app if per_app[a]]
-        ai = 0
-        for slot_idx in vacancies:
-            while app_names and not per_app[app_names[ai % len(app_names)]]:
-                app_names.pop(ai % len(app_names))
-            if not app_names:
-                break
-            app_name = app_names[ai % len(app_names)]
-            inst = per_app[app_name].pop(0)
-            old = self.slots[slot_idx]
-            if old is not None:
-                self._slot_idx.pop(old.instance_id, None)
-            self.slots[slot_idx] = CacheSlot(
-                instance_id=inst.id, job_id=inst.job_id, app_name=app_name
-            )
-            self._slot_idx[inst.id] = slot_idx
-            in_cache.add(inst.id)
-            filled += 1
-            ai += 1
-        cleared = 0
-        for i in stale:
-            s = self.slots[i]
-            if s is not None and self._stale(s):
-                self._slot_idx.pop(s.instance_id, None)
-                self.slots[i] = None
-                cleared += 1
-        if filled or cleared:
-            self.invalidate()
-        return filled
+        with span("boinc.feeder.fill"):
+            in_cache = {s.instance_id for s in self.slots if s is not None}
+            stale = [i for i, s in enumerate(self.slots) if s is not None and self._stale(s)]
+            vacancies = [i for i, s in enumerate(self.slots) if s is None or self._stale(s)]
+            if not vacancies:
+                return 0
+            per_app: Dict[str, List[JobInstance]] = {}
+            for app_name in self.store.apps:
+                # exclude in-cache ids *inside* the queue walk: with a backlog
+                # larger than the cache, the oldest UNSENT rows are exactly the
+                # cached ones, and filtering after the limit would starve refills
+                per_app[app_name] = self.store.unsent_instances(
+                    app_name, limit=len(vacancies), exclude=in_cache
+                )
+            filled = 0
+            app_names = [a for a in per_app if per_app[a]]
+            ai = 0
+            for slot_idx in vacancies:
+                while app_names and not per_app[app_names[ai % len(app_names)]]:
+                    app_names.pop(ai % len(app_names))
+                if not app_names:
+                    break
+                app_name = app_names[ai % len(app_names)]
+                inst = per_app[app_name].pop(0)
+                old = self.slots[slot_idx]
+                if old is not None:
+                    self._slot_idx.pop(old.instance_id, None)
+                self.slots[slot_idx] = CacheSlot(
+                    instance_id=inst.id, job_id=inst.job_id, app_name=app_name
+                )
+                self._slot_idx[inst.id] = slot_idx
+                in_cache.add(inst.id)
+                filled += 1
+                ai += 1
+            cleared = 0
+            for i in stale:
+                s = self.slots[i]
+                if s is not None and self._stale(s):
+                    self._slot_idx.pop(s.instance_id, None)
+                    self.slots[i] = None
+                    cleared += 1
+            if filled or cleared:
+                self.invalidate()
+            return filled
 
     def _stale(self, slot: CacheSlot) -> bool:
         inst = self.store.instances.get(slot.instance_id)
@@ -325,10 +327,11 @@ class Scheduler:
             or engine.backend != self.engine_backend
         ):
             # the constructor stamps the snapshot with feeder.version
-            engine = BatchDispatchEngine(self.store, feeder,
-                                         backend=self.engine_backend,
-                                         shard_map=self.shard_map,
-                                         shard=key)
+            with span("boinc.sched.snapshot_build", shard=self.shard):
+                engine = BatchDispatchEngine(self.store, feeder,
+                                             backend=self.engine_backend,
+                                             shard_map=self.shard_map,
+                                             shard=key)
             feeder._engines[key] = engine
         return engine
 

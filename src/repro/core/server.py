@@ -23,6 +23,7 @@ from .fsm import Transitioner
 from .scheduler import Feeder, Scheduler, ScheduleReply, ScheduleRequest, TrickleUp
 from .shard import ShardMap, ShardPolicy
 from .store import JobStore
+from .tracing import span
 from .types import App, AppVersion, Batch, Host, Job, next_id
 
 AssimilatorFn = Callable[[Job, Any], None]
@@ -194,19 +195,21 @@ class ProjectServer:
     # ------------------------------------------------------------------
 
     def rpc(self, request: ScheduleRequest, now: float) -> ScheduleReply:
-        self._handle_trickles(request, now)
-        if self.shard_map is not None:
-            # federated dispatch: stable host→shard affinity replaces the
-            # round-robin rotation, so a host always hits the same shard's
-            # cache slice (and the same scheduler RNG stream)
-            shard = self.shard_map.shard_of(request.host_id)
-            self.shard_map.rebalance(self.feeder, shard)
-            reply = self.schedulers[shard].handle_request(request, now)
-            self.shard_map.note(shard, requests=1, dispatched=len(reply.jobs))
-            return reply
-        sched = self.schedulers[self._rr % len(self.schedulers)]
-        self._rr += 1
-        return sched.handle_request(request, now)
+        with span("boinc.server.rpc_batch", requests=1):
+            self._handle_trickles(request, now)
+            if self.shard_map is not None:
+                # federated dispatch: stable host→shard affinity replaces the
+                # round-robin rotation, so a host always hits the same shard's
+                # cache slice (and the same scheduler RNG stream)
+                shard = self.shard_map.shard_of(request.host_id)
+                with span("boinc.server.shard_pass", shard=shard, requests=1):
+                    self.shard_map.rebalance(self.feeder, shard)
+                    reply = self.schedulers[shard].handle_request(request, now)
+                self.shard_map.note(shard, requests=1, dispatched=len(reply.jobs))
+                return reply
+            sched = self.schedulers[self._rr % len(self.schedulers)]
+            self._rr += 1
+            return sched.handle_request(request, now)
 
     def rpc_batch(self, requests: List[ScheduleRequest], now: float) -> List[ScheduleReply]:
         """Coalesced scheduler RPCs: one vectorized batch-dispatch pass.
@@ -230,22 +233,23 @@ class ProjectServer:
         so batching would change assignments — fall back to per-request
         dispatch to keep the identity.
         """
-        if len(self.schedulers) > 1:
-            if self.shard_map is None:
-                return [self.rpc(r, now) for r in requests]
-            return self._rpc_batch_sharded(requests, now)
-        for request in requests:
-            self._handle_trickles(request, now)
-        if not requests:
-            return []
-        sched = self.schedulers[self._rr % len(self.schedulers)]
-        self._rr += 1
-        # adaptive-replication decisions in this coalesced pass consume one
-        # prefetched RNG batch instead of interleaved per-job draws (§3.4);
-        # the FIFO cache preserves stream order, so every decision is
-        # identical to unbatched use regardless of the estimate's accuracy
-        self.adaptive.prefetch_draws(len(requests))
-        return sched.handle_batch(requests, now)
+        with span("boinc.server.rpc_batch", requests=len(requests)):
+            if len(self.schedulers) > 1:
+                if self.shard_map is None:
+                    return [self.rpc(r, now) for r in requests]
+                return self._rpc_batch_sharded(requests, now)
+            for request in requests:
+                self._handle_trickles(request, now)
+            if not requests:
+                return []
+            sched = self.schedulers[self._rr % len(self.schedulers)]
+            self._rr += 1
+            # adaptive-replication decisions in this coalesced pass consume one
+            # prefetched RNG batch instead of interleaved per-job draws (§3.4);
+            # the FIFO cache preserves stream order, so every decision is
+            # identical to unbatched use regardless of the estimate's accuracy
+            self.adaptive.prefetch_draws(len(requests))
+            return sched.handle_batch(requests, now)
 
     def _rpc_batch_sharded(
         self, requests: List[ScheduleRequest], now: float
@@ -266,13 +270,14 @@ class ProjectServer:
         replies: List[Optional[ScheduleReply]] = [None] * len(requests)
         for s in sorted(groups):
             idxs = groups[s]
-            # starved-shard migration before the pass, so a drained slice
-            # can steal neighbors' cached slots instead of replying empty
-            self.shard_map.rebalance(self.feeder, s)
-            # one prefetched adaptive-RNG batch per shard pass (same FIFO
-            # stream-order guarantee as the single-instance coalesced path)
-            self.adaptive.prefetch_draws(len(idxs))
-            out = self.schedulers[s].handle_batch([requests[i] for i in idxs], now)
+            with span("boinc.server.shard_pass", shard=s, requests=len(idxs)):
+                # starved-shard migration before the pass, so a drained slice
+                # can steal neighbors' cached slots instead of replying empty
+                self.shard_map.rebalance(self.feeder, s)
+                # one prefetched adaptive-RNG batch per shard pass (same FIFO
+                # stream-order guarantee as the single-instance coalesced path)
+                self.adaptive.prefetch_draws(len(idxs))
+                out = self.schedulers[s].handle_batch([requests[i] for i in idxs], now)
             dispatched = 0
             for i, reply in zip(idxs, out):
                 replies[i] = reply

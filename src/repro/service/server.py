@@ -16,10 +16,12 @@ touched only from the dispatcher task, and "now" comes from an injected
 from __future__ import annotations
 
 import asyncio
+import time
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional
 
 from ..core.server import ProjectServer
+from ..core.tracing import CompileCounter, span
 from .protocol import (
     MAX_LINE,
     ErrorReply,
@@ -40,6 +42,7 @@ class _Pending:
     seq: int
     request: object  # ScheduleRequest
     writer: asyncio.StreamWriter
+    enqueued: float  # time.perf_counter() when the frame was queued
 
 
 class SchedulerService:
@@ -73,7 +76,11 @@ class SchedulerService:
             "dispatched": 0,
             "errors": 0,
             "max_wave": 0,
+            # seconds dispatched requests spent queued, from enqueue to the
+            # start of their wave
+            "queue_wait_s": 0.0,
         }
+        self._compiles = CompileCounter.get()
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -100,8 +107,13 @@ class SchedulerService:
             await self._server.wait_closed()
             self._server = None
 
+    def _counters(self) -> Dict[str, object]:
+        # ``compiles``: jit compile requests in this process since the first
+        # service was made; one inside a live service is a recompile
+        return dict(self._stats, compiles=self._compiles.compiles)
+
     def stats(self) -> Dict[str, object]:
-        out: Dict[str, object] = dict(self._stats)
+        out = self._counters()
         if self.project.shard_map is not None:
             out["shards"] = self.project.shard_map.utilization()
         return out
@@ -113,7 +125,7 @@ class SchedulerService:
             writer.write((encode_reply(reply) + "\n").encode())
 
     def _flat_stats(self) -> Dict[str, float]:
-        vals = {k: float(v) for k, v in self._stats.items()}
+        vals = {k: float(v) for k, v in self._counters().items()}
         if self.project.shard_map is not None:
             for row in self.project.shard_map.utilization():
                 s = row["shard"]
@@ -140,7 +152,9 @@ class SchedulerService:
                     break
                 line = raw.decode("utf-8", errors="replace").rstrip("\r\n")
                 try:
-                    req = decode_request(line)
+                    with span("boinc.svc.decode") as sp:
+                        req = decode_request(line)
+                        sp.set_metadata(seq=req.seq)
                 except ProtocolError as e:
                     self._stats["errors"] += 1
                     self._send(writer, ErrorReply(0, e.code, e.message))
@@ -154,7 +168,9 @@ class SchedulerService:
                     await writer.drain()
                 else:
                     assert isinstance(req, WorkRequest)
-                    await self._queue.put(_Pending(req.seq, req.request, writer))
+                    await self._queue.put(
+                        _Pending(req.seq, req.request, writer, time.perf_counter())
+                    )
         except ConnectionError:
             pass
         finally:
@@ -174,6 +190,8 @@ class SchedulerService:
                     wave.append(self._queue.get_nowait())
                 except asyncio.QueueEmpty:
                     break
+            t_wave = time.perf_counter()
+            self._stats["queue_wait_s"] += sum(t_wave - p.enqueued for p in wave)
             now = self.clock()
             requests = [p.request for p in wave]
             if self.coalesce and len(requests) > 1:
@@ -182,10 +200,11 @@ class SchedulerService:
                 replies = [self.project.rpc(r, now) for r in requests]
             dispatched = 0
             writers = {}
-            for p, rep in zip(wave, replies):
-                dispatched += len(rep.jobs)
-                self._send(p.writer, reply_to_wire(p.seq, rep))
-                writers[id(p.writer)] = p.writer
+            with span("boinc.svc.encode"):
+                for p, rep in zip(wave, replies):
+                    dispatched += len(rep.jobs)
+                    self._send(p.writer, reply_to_wire(p.seq, rep))
+                    writers[id(p.writer)] = p.writer
             for w in writers.values():
                 try:
                     await w.drain()

@@ -318,3 +318,54 @@ class TestSchedulerService:
         assert isinstance(second, WorkReply)
         inst = server.store.instances[inst_id]
         assert not inst.is_outstanding()
+
+    def test_stats_export_queue_wait_and_compiles(self):
+        # queue_wait_s sums, over dispatched requests, the time from the
+        # frame's enqueue to the start of its wave: it never falls and
+        # grows with every request that passes through the queue
+        server = _make_project(n_sched=1, n_jobs=60, n_hosts=8)
+
+        async def main():
+            svc = SchedulerService(server)
+            await svc.start()
+            try:
+                reader, writer = await asyncio.open_connection("127.0.0.1", svc.port)
+                seq = 0
+
+                async def stats():
+                    nonlocal seq
+                    seq += 1
+                    writer.write(f"STATS {seq}\n".encode())
+                    await writer.drain()
+                    return decode_reply((await reader.readline()).decode().rstrip("\n"))
+
+                async def work(n):
+                    nonlocal seq
+                    for _ in range(n):
+                        seq += 1
+                        writer.write(
+                            f"WORK {seq} host={seq % 8 + 1} disk=1e+15 "
+                            f"cpu=3000.0:1.0:0.0\n".encode()
+                        )
+                    await writer.drain()
+                    for _ in range(n):
+                        await reader.readline()
+
+                before = await stats()
+                await work(1)
+                one = await stats()
+                await work(12)
+                many = await stats()
+                writer.close()
+            finally:
+                await svc.stop()
+            return before, one, many, svc.stats()
+
+        before, one, many, direct = asyncio.run(main())
+        assert before.values["queue_wait_s"] == 0.0
+        assert one.values["requests"] == 1.0 and one.values["queue_wait_s"] > 0.0
+        assert many.values["requests"] == 13.0
+        assert many.values["queue_wait_s"] > one.values["queue_wait_s"]
+        assert many.values["compiles"] >= 0.0
+        assert direct["queue_wait_s"] == many.values["queue_wait_s"]
+        assert isinstance(direct["compiles"], int)

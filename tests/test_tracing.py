@@ -1,0 +1,97 @@
+"""The program's own spans and compile counter (``core/tracing.py``): the
+validation pass's spans as the profiler records them on the CPU, the
+no-JAX fallback, and the one compile counter ``chip_smoke.py`` shares."""
+import importlib.util
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+
+from repro.core import jax_backend, tracing  # noqa: E402
+
+
+def _record(fn, log_dir):
+    """Run ``fn`` under the profiler; (its result, the ``boinc.*`` host
+    spans of the trace as (name, t0, t1, stats))."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(Path(log_dir).rglob("*.xplane.pb"))[-1]
+    spans = [
+        (e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+        for plane in jax.profiler.ProfileData.from_file(str(path)).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+        if e.name.startswith("boinc.")
+    ]
+    return out, spans
+
+
+def _inside(child, parent):
+    return parent[1] <= child[1] and child[2] <= parent[2]
+
+
+def test_quorum_group_codes_spans_a_corrupt_honest_honest_job(tmp_path):
+    # replica 0 corrupt, 1 and 2 honest: row 1 founds a second group after
+    # one comparison, row 2 is compared with both representatives
+    rng = np.random.default_rng(7)
+    honest = rng.standard_normal(3000).astype(np.float32)
+    corrupt = honest.copy()
+    corrupt[[5, 1700, 2999]] += 1.5
+    mat = np.stack([corrupt, honest, honest * np.float32(1 + 1e-7)])
+    want = jax_backend.quorum_group_codes(mat, 1e-4, 1e-6)
+    codes, spans = _record(lambda: jax_backend.quorum_group_codes(mat, 1e-4, 1e-6),
+                           tmp_path)
+    assert codes.tolist() == want.tolist() == [0, 1, 1]
+    by = {n: [s for s in spans if s[0] == n] for n in
+          ("boinc.validate.pair", "boinc.validate.upload", "boinc.validate.stack")}
+    assert len(by["boinc.validate.pair"]) == 3
+    assert len(by["boinc.validate.upload"]) == 3
+    for pair in by["boinc.validate.pair"]:
+        assert sum(_inside(u, pair) for u in by["boinc.validate.upload"]) == 1
+    assert len(by["boinc.validate.stack"]) == 1
+    assert not any(_inside(s, p) for s in by["boinc.validate.stack"]
+                   for p in by["boinc.validate.pair"])
+
+
+def test_a_span_records_its_metadata_as_stats(tmp_path):
+    def body():
+        with tracing.span("boinc.test.outer", shard=3):
+            with tracing.span("boinc.test.inner") as sp:
+                sp.set_metadata(seq=11)
+
+    _, spans = _record(body, tmp_path)
+    outer, = [s for s in spans if s[0] == "boinc.test.outer"]
+    inner, = [s for s in spans if s[0] == "boinc.test.inner"]
+    assert outer[3] == {"shard": 3} and inner[3] == {"seq": 11}
+    assert _inside(inner, outer)
+
+
+def test_without_jax_a_span_does_nothing(monkeypatch):
+    monkeypatch.setattr(jax_backend, "HAVE_JAX", False)
+    sp = tracing.span("boinc.test.none", shard=1)
+    with sp as entered:
+        entered.set_metadata(seq=1)
+    with pytest.raises(KeyError):
+        with tracing.span("boinc.test.none"):
+            raise KeyError("propagates")
+
+
+def test_one_compile_counter_counts_a_new_shape():
+    counter = tracing.CompileCounter.get()
+    assert tracing.CompileCounter.get() is counter
+    c0 = counter.snapshot()
+    jax.jit(lambda x: x * 3 + 1)(np.arange(13.0)).block_until_ready()
+    assert counter.snapshot()[0] > c0[0]
+    path = Path(__file__).resolve().parent.parent / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke_counter", path)
+    chip_smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(chip_smoke)
+    assert chip_smoke.CompileCounter is tracing.CompileCounter
