@@ -13,7 +13,9 @@ module processes **every dirty job of a tick at once**:
     for plain-float payloads; fused mantissa-truncation buckets + row hash
     for homogeneous tensor payloads; 8-byte blake2b otherwise — see
     ``validator.py`` for the digest contracts), batched per app across all
-    jobs of the tick;
+    jobs of the tick; a pairwise hook (the JAX backend's kernel grouping)
+    gets each job's row offsets too and compares within a job only, so its
+    codes are consistent within a job, which is all the grouping reads;
   * equivalence grouping as a single ``lexsort`` over ``(job, digest)``
     keys instead of pairwise comparator loops; quorum / canonical
     decisions for all candidate jobs in one boolean-mask pass, with the
@@ -190,6 +192,13 @@ class ValidationPlan:
         return max(counts.values())
 
 
+def _job_offsets(row_jobs: np.ndarray) -> List[int]:
+    """Row offsets of each job's run in ``row_jobs`` (job positions, in
+    order), and the end: job k's rows are ``off[k]:off[k + 1]``."""
+    cut = np.flatnonzero(row_jobs[1:] != row_jobs[:-1]) + 1
+    return [0, *cut.tolist(), len(row_jobs)]
+
+
 def _scalar_largest_group(app: App, successes: List[JobInstance]) -> int:
     from .fsm import Transitioner
 
@@ -358,8 +367,15 @@ class BatchValidationEngine:
                 )
                 fn = self.digest_fn(store.apps[app_name])
                 if fn is not None:
+                    rows = list(idxs)
+                    outs = [refs[drows[k]].output for k in rows]
                     try:
-                        digall[list(idxs)] = fn([refs[drows[k]].output for k in idxs])
+                        if getattr(fn, "pairwise", False):
+                            # a pairwise route compares results: it gets
+                            # each job's rows apart, never another job's
+                            digall[rows] = fn(outs, job_off=_job_offsets(djob[rows]))
+                        else:
+                            digall[rows] = fn(outs)
                         continue
                     except DigestError:
                         pass

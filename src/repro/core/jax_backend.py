@@ -629,7 +629,9 @@ class WorldDeviceMirror:
 def quorum_group_codes(mat: np.ndarray, rtol: float, atol: float) -> np.ndarray:
     """Group codes for a homogeneous (n, d) float payload matrix via the
     ``kernels/quorum_compare`` Pallas kernel (compiled on the chip,
-    interpret mode on the CPU backend only).
+    interpret mode on the CPU backend only). ``mat`` holds one job's
+    results: codes are consistent within a job only, and the engine never
+    compares them across jobs (its quorum grouping keys on ``(job, code)``).
 
     Greedy first-match grouping: row i joins the first group whose
     representative it agrees with (kernel verdict ``n_bad == 0`` under the
@@ -676,16 +678,32 @@ def quorum_group_codes(mat: np.ndarray, rtol: float, atol: float) -> np.ndarray:
 
 def fuzzy_digest_jax(base, rtol: float, atol: float):
     """Wrap a fuzzy comparator's digest hook: homogeneous float tensor
-    payload batches route through the Pallas kernel grouping; everything
-    else (plain floats, mixed payloads) falls through to ``base``."""
+    payloads route through the Pallas kernel grouping, one job at a time;
+    everything else (plain floats, mixed payloads) falls through to
+    ``base`` in one call.
+
+    ``job_off`` gives the row offsets of each job's results in ``outputs``
+    (``job_off[k]:job_off[k + 1]`` is job k); without it ``outputs`` is one
+    job. The hook is marked ``pairwise`` so the engine passes them."""
     from .validator import _homogeneous_arrays
 
-    def fn(outputs: Sequence) -> np.ndarray:
-        if len(outputs) >= 2 and isinstance(outputs[0], np.ndarray):
+    def fn(outputs: Sequence, job_off: Optional[Sequence[int]] = None) -> np.ndarray:
+        if len(outputs) < 2 or not isinstance(outputs[0], np.ndarray):
+            return base(outputs)
+        bounds = (0, len(outputs)) if job_off is None else job_off
+        codes = np.zeros(len(outputs), dtype=np.int64)
+        rest: List[int] = []
+        for a, b in zip(bounds[:-1], bounds[1:]):
             with tracing.span("boinc.validate.stack"):
-                mat = _homogeneous_arrays(outputs)
-            if mat is not None and mat.dtype.kind == "f":
-                return quorum_group_codes(mat, rtol, atol)
-        return base(outputs)
+                mat = _homogeneous_arrays(outputs[a:b])
+            if mat is None or mat.dtype.kind != "f":
+                rest.extend(range(a, b))
+                continue
+            with tracing.span("boinc.validate.group", rows=b - a):
+                codes[a:b] = quorum_group_codes(mat, rtol, atol)
+        if rest:
+            codes[rest] = base([outputs[k] for k in rest])
+        return codes
 
+    fn.pairwise = True
     return fn

@@ -308,6 +308,117 @@ class TestTickParity:
         assert ValidateState.INVALID in states  # disagreeing stragglers seen
 
 
+class TestKernelGroupingWithinJobs:
+    """The JAX engine groups tensor results through the Pallas kernel one
+    job at a time: a tick compares each job's results only with each
+    other, in the greedy order, and decides what the scalar path does."""
+
+    D = 700  # elements a result: past two 256-lane rows
+
+    def _payloads(self):
+        rs = np.random.RandomState(5)
+        honest = [rs.standard_normal(self.D).astype(np.float32) for _ in range(7)]
+
+        def corrupt(x, at):
+            y = x.copy()
+            y[at] += np.float32(1.5)
+            return y
+
+        nan = honest[3].copy()
+        nan[self.D - 1] = np.nan
+        return [
+            [honest[0], honest[0]],                                    # honest pair
+            [honest[1], honest[1] * np.float32(1 + 1e-7)],            # honest pair
+            [corrupt(honest[2], [0, 350]), honest[2], honest[2]],      # C, H, H
+            [honest[3], nan, honest[3]],                               # a NaN replica
+            [honest[0], honest[0]],                                    # job 0's payload
+            [honest[0], honest[5], honest[5]],                         # job 0's, outvoted
+            [honest[6], corrupt(honest[6], [self.D - 1]), honest[6]],  # H, C, H
+        ]
+
+    def _build(self, outputs, batch_validate):
+        store, tr = build_pending(n_jobs=0, batch_validate=batch_validate)
+        app = store.apps["w"]
+        vid = next(iter(store.app_versions))
+        jobs = []
+        for j, outs in enumerate(outputs):
+            job = Job(id=next_id("job"), app_name="w", est_flop_count=1e12,
+                      min_quorum=2, init_ninstances=2,
+                      max_success_instances=app.max_success_instances)
+            store.submit_job(job)
+            for k, out in enumerate(outs):
+                inst = store.create_instance(job)
+                inst.host_id = 3 * j + k + 1
+                inst.app_version_id = vid
+                inst.state = InstanceState.IN_PROGRESS
+                inst.state = InstanceState.OVER
+                inst.outcome = InstanceOutcome.SUCCESS
+                inst.runtime = 750.0
+                inst.peak_flop_count = inst.runtime * 16.5e9
+                inst.output = out
+            jobs.append(job)
+        if batch_validate:
+            tr.engine_backend = "jax"  # the engine is built on the first tick
+        return store, tr, jobs
+
+    @staticmethod
+    def _greedy_comparisons(outs, same):
+        """Comparisons the pinned greedy grouping makes inside one job; a
+        NaN-carrying result is compared with nothing."""
+        n, reps = 0, []
+        for x in outs:
+            if np.isnan(x).any():
+                continue
+            for r in reps:
+                n += 1
+                if same(x, r):
+                    break
+            else:
+                reps.append(x)
+        return n
+
+    def test_one_tick_compares_within_each_job_only(self, monkeypatch):
+        pytest.importorskip("jax")
+        from repro.kernels.quorum_compare import ops
+
+        calls = {"n": 0}
+        real = ops.quorum_compare
+
+        def counting(*a, **kw):
+            calls["n"] += 1
+            return real(*a, **kw)
+
+        monkeypatch.setattr(ops, "quorum_compare", counting)
+        outputs = self._payloads()
+
+        sa, ta, jobs_a = self._build(outputs, batch_validate=False)
+        ta.tick(60.0)
+        snap_a = snapshot(sa, ta)
+        sb, tb, jobs_b = self._build(outputs, batch_validate=True)
+        tb.tick(60.0)
+        snap_b = snapshot(sb, tb)
+
+        same = sb.apps["w"].comparator
+        assert calls["n"] == sum(self._greedy_comparisons(o, same) for o in outputs) == 12
+        assert snap_a == snap_b
+        sb.check_invariants()
+
+        def states(job):
+            return [i.validate_state for i in sb.job_instances(job.id)]
+
+        v, x = ValidateState.VALID, ValidateState.INVALID
+        assert states(jobs_b[2]) == [x, v, v]
+        assert states(jobs_b[3]) == [v, x, v]
+        assert states(jobs_b[6]) == [v, x, v]
+        # jobs 0 and 4 hold bit-identical payloads, and job 5 holds the same
+        # payload beside two others: each job gets verdicts of its own
+        assert states(jobs_b[0]) == states(jobs_b[4]) == [v, v]
+        assert states(jobs_b[5]) == [x, v, v]
+        canon = [sb.jobs[j.id].canonical_instance_id for j in jobs_b]
+        assert len(set(canon)) == len(jobs_b)
+        assert canon[5] == sb.job_instances(jobs_b[5].id)[1].id
+
+
 # ---------------------------------------------------------------------------
 # whole-simulation twins (the acceptance-criterion parity)
 # ---------------------------------------------------------------------------

@@ -35,7 +35,7 @@ try:  # optional dep: see requirements-dev.txt
 except ImportError:
     HAVE_HYPOTHESIS = False
 
-from repro.core import BatchClientEngine, ResourceType
+from repro.core import BatchClientEngine, ResourceType, jax_backend
 from repro.core.client import (
     Client,
     ClientJob,
@@ -48,6 +48,7 @@ from repro.core.jax_backend import (
     HAVE_JAX,
     dispatch_elig,
     dispatch_scores,
+    fuzzy_digest_jax,
     quorum_group_codes,
     resolve_backend,
 )
@@ -290,6 +291,48 @@ def test_quorum_digest_negative_zero_and_nan_exact():
     assert codes[2] != codes[0]
     assert len({int(x) for x in codes}) == 4  # {a,b}, {c}, {nan1}, {nan2}
     assert codes[3] != codes[4]
+
+
+def test_fuzzy_digest_jax_groups_each_job_apart(monkeypatch):
+    """With job offsets the kernel route compares rows of one job only: two
+    jobs of one payload each found their own group 0, and each job's codes
+    partition it as ``quorum_group_codes`` does alone."""
+    rs = np.random.RandomState(2)
+    x, y = rs.standard_normal(40), rs.standard_normal(40)
+    outputs = [x, x, y, y, x, x + 50.0, y]
+    off = [0, 2, 4, 7]
+    seen = []
+
+    def base(outs):
+        raise AssertionError("tensor rows never reach the scalar digest")
+
+    fn = fuzzy_digest_jax(base, 1e-5, 1e-8)
+    assert fn.pairwise
+    monkeypatch.setattr(jax_backend, "quorum_group_codes",
+                        lambda m, r, a: seen.append(len(m)) or quorum_group_codes(m, r, a))
+    codes = fn(outputs, job_off=off)
+    assert seen == [2, 2, 3]
+    assert codes.tolist() == [0, 0, 0, 0, 0, 1, 2]
+    whole = fn(outputs)  # no offsets: one job
+    assert _partition(whole) == [(0, 1, 4), (2, 3, 6), (5,)]
+
+
+def test_fuzzy_digest_jax_sends_other_payloads_to_the_base_hook_once():
+    calls = []
+
+    def base(outs):
+        calls.append(list(outs))
+        return np.arange(len(outs), dtype=np.int64) + 100
+
+    fn = fuzzy_digest_jax(base, 1e-5, 1e-8)
+    floats = [1.0, 1.0, 2.0, 2.0]
+    assert fn(floats, job_off=[0, 2, 4]).tolist() == [100, 101, 102, 103]
+    assert calls == [floats]
+    # a job of mixed shapes goes to the base hook; the tensor jobs do not
+    a, b = np.ones(8), np.ones(9)
+    codes = fn([a, a, a, b, a, a], job_off=[0, 2, 4, 6])
+    assert calls[1] == [a, b]
+    assert codes.tolist() == [0, 0, 100, 101, 0, 0]
 
 
 # ---------------------------------------------------------------------------

@@ -61,6 +61,23 @@ def test_quorum_group_codes_spans_a_corrupt_honest_honest_job(tmp_path):
                    for p in by["boinc.validate.pair"])
 
 
+def test_each_job_is_grouped_in_a_span_of_its_own(tmp_path):
+    # three jobs of 2, 3 and 2 rows through the engine's pairwise hook
+    rng = np.random.default_rng(8)
+    a, b, c = (rng.standard_normal(600).astype(np.float32) for _ in range(3))
+    bad = b.copy()
+    bad[599] += 2.0
+    outputs = [a, a, bad, b, b, c, c]
+    fn = jax_backend.fuzzy_digest_jax(lambda outs: 1 / 0, 1e-4, 1e-6)
+    codes, spans = _record(lambda: fn(outputs, job_off=[0, 2, 5, 7]), tmp_path)
+    assert codes.tolist() == [0, 0, 0, 1, 1, 0, 0]
+    groups = [s for s in spans if s[0] == "boinc.validate.group"]
+    pairs = [s for s in spans if s[0] == "boinc.validate.pair"]
+    assert [g[3] for g in groups] == [{"rows": 2}, {"rows": 3}, {"rows": 2}]
+    assert [sum(_inside(p, g) for p in pairs) for g in groups] == [1, 3, 1]
+    assert len(pairs) == 5
+
+
 def test_a_span_records_its_metadata_as_stats(tmp_path):
     def body():
         with tracing.span("boinc.test.outer", shard=3):
