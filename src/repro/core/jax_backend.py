@@ -305,6 +305,18 @@ if HAVE_JAX:
     def _k_vec_upload(dev, host_vals, cols):
         return dev.at[cols].set(host_vals)
 
+    # ------------------------------------------------------------------
+    # validation staging (fuzzy_digest_jax / quorum_group_codes)
+    # ------------------------------------------------------------------
+
+    @jax.jit
+    def _k_stack_rows(*rows):
+        return jnp.stack([r.reshape(-1) for r in rows])
+
+    @jax.jit
+    def _k_nan_rows(mat):
+        return jnp.isnan(mat).any(axis=1)
+
 
 # ----------------------------------------------------------------------
 # dispatch wrappers
@@ -626,12 +638,15 @@ class WorldDeviceMirror:
 # ----------------------------------------------------------------------
 
 
-def quorum_group_codes(mat: np.ndarray, rtol: float, atol: float) -> np.ndarray:
+def quorum_group_codes(mat, rtol: float, atol: float) -> np.ndarray:
     """Group codes for a homogeneous (n, d) float payload matrix via the
     ``kernels/quorum_compare`` Pallas kernel (compiled on the chip,
     interpret mode on the CPU backend only). ``mat`` holds one job's
     results: codes are consistent within a job only, and the engine never
     compares them across jobs (its quorum grouping keys on ``(job, code)``).
+    It is a matrix on the device, as ``fuzzy_digest_jax`` stages it, or a
+    host matrix, which is put on the device once, here: no comparison
+    uploads a row.
 
     Greedy first-match grouping: row i joins the first group whose
     representative it agrees with (kernel verdict ``n_bad == 0`` under the
@@ -641,7 +656,8 @@ def quorum_group_codes(mat: np.ndarray, rtol: float, atol: float) -> np.ndarray:
     pairwise grouping. The kernel compares in f32 — another reason the
     far-from-boundary contract is load-bearing. NaN-carrying rows match
     nothing (kernel predicate is False for NaN, which would read as
-    agreement) and get unique sentinels, mirroring ``_fuzzy_digest_*``.
+    agreement) and get unique sentinels, mirroring ``_fuzzy_digest_*``;
+    the chip flags them, and the flags are read back in one sync.
     """
     from ..kernels.quorum_compare.ops import quorum_compare
     from .validator import _nan_sentinel
@@ -650,7 +666,9 @@ def quorum_group_codes(mat: np.ndarray, rtol: float, atol: float) -> np.ndarray:
     codes = np.zeros(n, dtype=np.int64)
     reps: List[int] = []
     with tracing.span("boinc.validate.stack"):
-        nan_rows = np.isnan(mat).any(axis=1)
+        if not isinstance(mat, jax.Array):
+            mat = jax.device_put(mat)
+        nan_rows = np.asarray(_k_nan_rows(mat))
     for i in range(n):
         if nan_rows[i]:
             codes[i] = _nan_sentinel()
@@ -658,11 +676,9 @@ def quorum_group_codes(mat: np.ndarray, rtol: float, atol: float) -> np.ndarray:
         assigned = False
         for g, r in enumerate(reps):
             with tracing.span("boinc.validate.pair"):
-                # the call's host side: staging both rows for their
-                # implicit upload, and the launch. The transfer and the
-                # kernel are waited for in int(n_bad). An explicit
-                # device_put here cost ~4% of the pass on a v5e, and
-                # waiting on it ~10%.
+                # the call's host side: slicing both rows out of the
+                # device matrix, and the launch. The kernel is waited for
+                # in int(n_bad).
                 with tracing.span("boinc.validate.upload"):
                     n_bad, _ = quorum_compare(mat[i], mat[r], rtol=rtol, atol=atol)
                 agree = int(n_bad) == 0
@@ -676,16 +692,32 @@ def quorum_group_codes(mat: np.ndarray, rtol: float, atol: float) -> np.ndarray:
     return codes
 
 
+def _device_matrix(outputs: Sequence):
+    """One job's payloads as an (n, size) matrix on the device, or None
+    unless they are homogeneous floats (``validator._homogeneous``). Each
+    payload is put on the device once, as it is, in a
+    ``boinc.validate.put`` span; the chip flattens and stacks them. No
+    host copy of a payload is made."""
+    from .validator import _homogeneous
+
+    if not (_homogeneous(outputs) and outputs[0].dtype.kind == "f"):
+        return None
+    rows = []
+    for o in outputs:
+        with tracing.span("boinc.validate.put"):
+            rows.append(jax.device_put(o))
+    return _k_stack_rows(*rows)
+
+
 def fuzzy_digest_jax(base, rtol: float, atol: float):
     """Wrap a fuzzy comparator's digest hook: homogeneous float tensor
-    payloads route through the Pallas kernel grouping, one job at a time;
-    everything else (plain floats, mixed payloads) falls through to
-    ``base`` in one call.
+    payloads route through the Pallas kernel grouping, one job at a time,
+    each job's payloads put on the device once; everything else (plain
+    floats, mixed payloads) falls through to ``base`` in one call.
 
     ``job_off`` gives the row offsets of each job's results in ``outputs``
     (``job_off[k]:job_off[k + 1]`` is job k); without it ``outputs`` is one
     job. The hook is marked ``pairwise`` so the engine passes them."""
-    from .validator import _homogeneous_arrays
 
     def fn(outputs: Sequence, job_off: Optional[Sequence[int]] = None) -> np.ndarray:
         if len(outputs) < 2 or not isinstance(outputs[0], np.ndarray):
@@ -695,8 +727,8 @@ def fuzzy_digest_jax(base, rtol: float, atol: float):
         rest: List[int] = []
         for a, b in zip(bounds[:-1], bounds[1:]):
             with tracing.span("boinc.validate.stack"):
-                mat = _homogeneous_arrays(outputs[a:b])
-            if mat is None or mat.dtype.kind != "f":
+                mat = _device_matrix(outputs[a:b])
+            if mat is None:
                 rest.extend(range(a, b))
                 continue
             with tracing.span("boinc.validate.group", rows=b - a):

@@ -16,8 +16,10 @@ Spans of the hot paths (the per-layer metrics of ``perfbench`` read them):
   ``boinc.feeder.fill``;
 * device path: ``boinc.sched.snapshot_build`` (``shard``),
   ``boinc.dispatch.device`` (``kernel``);
-* validation: ``boinc.validate.stack``, ``boinc.validate.pair`` and its
-  child ``boinc.validate.upload``.
+* validation: ``boinc.validate.stack`` and its child
+  ``boinc.validate.put`` (one a result), ``boinc.validate.group``
+  (``rows``), ``boinc.validate.pair`` and its child
+  ``boinc.validate.upload``.
 """
 from __future__ import annotations
 

@@ -225,17 +225,22 @@ def _bitwise_digest_one(out: Any) -> int:
         return _nan_sentinel()
 
 
-def _homogeneous_arrays(outputs: Sequence[Any]) -> Optional[np.ndarray]:
-    """Stack payloads that are all ndarrays of one dtype and shape (the
-    typical tensor-result population) into an (n, size) matrix; None when
-    the population is mixed."""
+def _homogeneous(outputs: Sequence[Any]) -> bool:
+    """True when the payloads are all ndarrays of one dtype and one shape
+    of at least one dimension (the typical tensor-result population)."""
     first = outputs[0]
     if not (isinstance(first, np.ndarray) and first.ndim >= 1):
-        return None
+        return False
     dt, shp = first.dtype, first.shape
-    for o in outputs:
-        if not isinstance(o, np.ndarray) or o.dtype != dt or o.shape != shp:
-            return None
+    return all(isinstance(o, np.ndarray) and o.dtype == dt and o.shape == shp
+               for o in outputs)
+
+
+def _homogeneous_arrays(outputs: Sequence[Any]) -> Optional[np.ndarray]:
+    """Stack homogeneous payloads (``_homogeneous``) into an (n, size)
+    matrix; None when the population is mixed."""
+    if not _homogeneous(outputs):
+        return None
     return np.stack(outputs).reshape(len(outputs), -1)
 
 

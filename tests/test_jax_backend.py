@@ -335,6 +335,96 @@ def test_fuzzy_digest_jax_sends_other_payloads_to_the_base_hook_once():
     assert codes.tolist() == [0, 0, 100, 101, 0, 0]
 
 
+class _NoHostScan:
+    """``numpy`` for ``jax_backend``, less the host stack and NaN scan."""
+
+    def __getattr__(self, name):
+        if name in ("stack", "isnan"):
+            raise AssertionError(f"np.{name} ran on the kernel route")
+        return getattr(np, name)
+
+
+def test_fuzzy_digest_jax_puts_each_result_on_the_device_once(monkeypatch):
+    """Jobs of 2, 3 (corrupt, honest, honest) and 2 rows: each payload goes
+    through ``jax.device_put`` exactly once, as itself, and the route makes
+    no host copy or scan of a job's payloads."""
+    from repro.core import validator
+
+    rng = np.random.default_rng(8)
+    a, b, c = (rng.standard_normal(600).astype(np.float32) for _ in range(3))
+    bad = b.copy()
+    bad[599] += 2.0
+    outputs = [a, a.copy(), bad, b, b.copy(), c, c.copy()]
+    off = [0, 2, 5, 7]
+    want = np.concatenate([_ref_group_codes(np.stack(outputs[i:j]), 1e-4, 1e-6)
+                           for i, j in zip(off[:-1], off[1:])])
+    put = []
+    real_put = jax.device_put
+
+    def counting_put(x, *args, **kw):
+        put.append(x)
+        return real_put(x, *args, **kw)
+
+    def no_stack(outs):
+        raise AssertionError("the kernel route stacked a job on the host")
+
+    monkeypatch.setattr(jax, "device_put", counting_put)
+    monkeypatch.setattr(validator, "_homogeneous_arrays", no_stack)
+    monkeypatch.setattr(jax_backend, "np", _NoHostScan())
+    codes = fuzzy_digest_jax(lambda outs: 1 / 0, 1e-4, 1e-6)(outputs, job_off=off)
+    assert codes.tolist() == want.tolist() == [0, 0, 0, 1, 1, 0, 0]
+    assert len(put) == 7
+    assert all(p is o for p, o in zip(put, outputs))
+
+
+_D = 3 * 256 + 72  # three full 256-lane rows and a tail of 72 elements
+
+
+def _nan_scan_case(case):
+    """(matrix, NaN rows) of one placement of a NaN or an inf."""
+    rs = np.random.RandomState(11)
+    x = rs.standard_normal(_D).astype(np.float32)
+    far = x + np.float32(50.0)
+    if case == "row0_nan":
+        r0 = x.copy()
+        r0[_D // 2] = np.nan
+        return np.stack([r0, x, x, far]), [0]
+    if case == "tail_nan":
+        r1 = x.copy()
+        r1[3 * 256 + 10] = np.nan
+        return np.stack([x, r1, x]), [1]
+    if case == "last_element_nan":
+        r2 = x.copy()
+        r2[-1] = np.nan
+        return np.stack([x, far, r2, far]), [2]
+    if case == "inf_row":
+        inf = x.copy()
+        inf[7] = np.inf
+        return np.stack([x, inf, inf.copy(), x, far]), []
+    if case == "float64_host":
+        r = x.astype(np.float64)
+        r3 = r.copy()
+        r3[100] = np.nan
+        return np.stack([r, r + 50.0, r, r3]), [3]
+    raise KeyError(case)
+
+
+@pytest.mark.parametrize("case", ["row0_nan", "tail_nan", "last_element_nan",
+                                  "inf_row", "float64_host"])
+def test_the_chip_flags_nan_rows_as_the_reference_grouping_does(case):
+    """The device NaN scan, as a host matrix and as the route stages it:
+    NaN rows are unique singletons and never representatives; the other
+    rows (an ``inf`` row among them) get the reference's codes."""
+    mat, nan_rows = _nan_scan_case(case)
+    want = _ref_group_codes(mat, 1e-4, 1e-6)
+    route = fuzzy_digest_jax(lambda outs: 1 / 0, 1e-4, 1e-6)
+    for got in (quorum_group_codes(mat, 1e-4, 1e-6), route(list(mat))):
+        assert _partition(got) == _partition(want)
+        finite = [i for i in range(len(mat)) if i not in nan_rows]
+        assert got[finite].tolist() == want[finite].tolist()
+        assert len({int(got[i]) for i in nan_rows} - set(got[finite].tolist())) == len(nan_rows)
+
+
 # ---------------------------------------------------------------------------
 # world device mirror: dirty-upload regression
 # ---------------------------------------------------------------------------

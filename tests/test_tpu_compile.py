@@ -117,3 +117,13 @@ def test_dispatch_est_scaled_compile(spec):
     v = spec((CACHE,), jnp.float64)
     compiled = jax_backend._k_est_scaled.lower(v, v, spec((), jnp.float64)).compile()
     _fits(compiled)
+
+
+@pytest.mark.parametrize("rows", [2, 3])
+def test_validation_staging_fits_one_chip(spec, rows):
+    # a job's results of one Mamba2-130M layer gradient (3,765,320 float32)
+    # stacked on the chip, then its NaN rows flagged
+    row = spec((3_765_320,), jnp.float32)
+    _fits(jax_backend._k_stack_rows.lower(*[row] * rows).compile())
+    mat = spec((rows, 3_765_320), jnp.float32)
+    _fits(jax_backend._k_nan_rows.lower(mat).compile())

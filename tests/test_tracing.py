@@ -78,6 +78,25 @@ def test_each_job_is_grouped_in_a_span_of_its_own(tmp_path):
     assert len(pairs) == 5
 
 
+def test_each_result_is_put_on_the_device_once_inside_a_stack_span(tmp_path):
+    # the same three jobs: seven results, one put span each, all inside the
+    # stack span of their own job
+    rng = np.random.default_rng(8)
+    a, b, c = (rng.standard_normal(600).astype(np.float32) for _ in range(3))
+    bad = b.copy()
+    bad[599] += 2.0
+    outputs = [a, a.copy(), bad, b, b.copy(), c, c.copy()]
+    fn = jax_backend.fuzzy_digest_jax(lambda outs: 1 / 0, 1e-4, 1e-6)
+    codes, spans = _record(lambda: fn(outputs, job_off=[0, 2, 5, 7]), tmp_path)
+    assert codes.tolist() == [0, 0, 0, 1, 1, 0, 0]
+    puts = [s for s in spans if s[0] == "boinc.validate.put"]
+    stacks = [s for s in spans if s[0] == "boinc.validate.stack"]
+    assert len(puts) == 7
+    assert all(sum(_inside(p, s) for s in stacks) == 1 for p in puts)
+    per_stack = [sum(_inside(p, s) for p in puts) for s in sorted(stacks, key=lambda s: s[1])]
+    assert [k for k in per_stack if k] == [2, 3, 2]
+
+
 def test_a_span_records_its_metadata_as_stats(tmp_path):
     def body():
         with tracing.span("boinc.test.outer", shard=3):
