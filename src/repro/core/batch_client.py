@@ -36,8 +36,7 @@ forms that add exact zeros, and the rare inputs where Python's ``min``/
 ``est_flops <= 0``) fall back to exact ``np.where`` folds. The engine is
 therefore *bit-exact* with the scalar oracle: identical run sets,
 deadline-miss sets, and work requests. ``tests/test_batch_client.py``
-asserts it, ``benchmarks/bench_clients.py`` measures the speedup
-(acceptance floor: ≥10× client tick cost at the 10k-host population).
+asserts it.
 Client state mutations (miss flags, run/preempt transitions) go through
 the same ``Client`` helpers as the scalar path.
 

@@ -388,8 +388,7 @@ def build(
     server = build_server(spec, batch_validate, backend=backend)
     pop = generate_population(spec)
     sim = GridSimulation(
-        server, pop, seed=spec.sim_seed, vector_world=vector_world, epoch=epoch,
-        backend=backend,
+        server, pop, seed=spec.sim_seed, vector_world=vector_world, epoch=epoch
     )
     per_wave = spec.n_jobs // spec.waves
 

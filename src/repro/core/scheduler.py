@@ -5,8 +5,9 @@ work; a shared-memory **job cache** of ~1000 unsent instances is replenished
 by a **feeder** daemon. The scheduler scans the cache (random start point to
 reduce lock conflict), scores candidates, re-checks under a mutex ("fast
 check"), then against the DB ("slow check"), and builds the reply. This is
-what lets one server dispatch hundreds of jobs per second [paper ref 17] —
-reproduced in ``benchmarks/bench_dispatch.py``.
+what lets one server dispatch hundreds of jobs per second [paper ref 17].
+The vectorized batch engine (``core/batch_dispatch.py``) takes the same
+decisions as the scalar scan, asserted by ``tests/test_batch_dispatch.py``.
 
 Policy (§6.4): GPUs handled first; app-version selection by max
 ``proj_flops`` among (platform, plan-class, HR)-compatible versions; score =

@@ -64,8 +64,9 @@ class ProjectServer:
     vector_dispatch: bool = False
     # execution backend for the batch engines ("numpy" | "jax"), handed to
     # every Scheduler (dispatch scoring) and Transitioner (validation
-    # digests); engine outputs are bit-identical either way (4th parity
-    # axis in core/scenarios.run_parity)
+    # digests), and read by a GridSimulation for its client engine and
+    # world; engine outputs are bit-identical either way (4th parity axis
+    # in core/scenarios.run_parity)
     engine_backend: str = "numpy"
     # defense-in-depth replica placement (§3.4): work-spreading, HR census
     # pinning, host punishment. None disables the layer entirely.
@@ -181,7 +182,7 @@ class ProjectServer:
 
     def submit_batch(self, jobs: List[Job], submitter: str, now: float = 0.0) -> Batch:
         """Batch submission (§3.9) — designed so a thousand jobs submit fast;
-        see benchmarks/bench_dispatch.py."""
+        ``tests/test_store_index.py`` checks its counters."""
         batch = Batch(id=next_id("batch"), submitter=submitter, created_time=now)
         self.store.batches[batch.id] = batch
         for j in jobs:

@@ -180,7 +180,7 @@ class ShardMap:
 
     def utilization(self) -> List[Dict[str, int]]:
         """Per-shard counters + current slot ownership, for the service
-        layer's ``stats()`` and ``BENCH_rpc.json``'s utilization rows."""
+        layer's ``stats()``; ``tests/test_shard_dispatch.py`` checks them."""
         counts = np.bincount(self.owner, minlength=self.n_shards)
         return [
             {

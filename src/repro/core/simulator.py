@@ -8,8 +8,8 @@ server software ... using virtual time instead of real time."
 
 This module does exactly that: a deterministic event-driven simulator whose
 host population drives the *actual* ``ProjectServer`` / ``Client`` /
-``Scheduler`` / ``Transitioner`` code in virtual time. All paper-claim
-benchmarks and the integration tests run on it.
+``Scheduler`` / ``Transitioner`` code in virtual time. The integration
+tests and the scenario matrix run on it.
 
 Two event loops share one world. Per-host state (availability,
 generation counters, running-instance accrual, the mirrored client queues)
@@ -31,7 +31,7 @@ between the two loops (``tests/test_world.py``).
 ``epoch`` quantizes event times up to a fixed grid (0 disables). Both
 loops share the quantization, so parity holds at any epoch; with it, event
 coalescing — and therefore the vectorized loop's advantage — grows with
-the population (``benchmarks/bench_world.py``).
+the population.
 """
 from __future__ import annotations
 
@@ -271,7 +271,6 @@ class GridSimulation:
         batch_clients: bool = True,
         vector_world: bool = True,
         epoch: float = 0.0,
-        backend: str = "numpy",
     ) -> None:
         self.server = server
         self.specs: Dict[int, HostSpec] = {s.host.id: s for s in population}
@@ -298,13 +297,12 @@ class GridSimulation:
         # event lands on the next multiple of ``epoch``. Applied in both
         # loops, so scalar-vs-vector parity holds at any epoch.
         self.epoch = epoch
-        # execution backend for the client/world batch engines ("numpy" |
-        # "jax"); engine outputs are bit-identical either way (4th parity
-        # axis in core/scenarios.run_parity). The server-side engines get
-        # their backend via ProjectServer(engine_backend=...).
-        self.backend = backend
-        self.client_engine = BatchClientEngine(backend=backend)
-        self.world = HostArrays(backend=backend)
+        # the client engine and the world run on the server's engine
+        # backend ("numpy" | "jax"), so one setting picks every kernel;
+        # outputs are bit-identical either way (4th parity axis in
+        # core/scenarios.run_parity)
+        self.client_engine = BatchClientEngine(backend=server.engine_backend)
+        self.world = HostArrays(backend=server.engine_backend)
         self.ground_truth = ground_truth or (lambda job_id: float(job_id) * 1.5)
         # real-compute hook (grid runtime): executor(job, host) -> output
         self.executor = executor

@@ -1,6 +1,6 @@
 """Builds jitted, sharded train / prefill / decode steps for any
 (architecture x shape x mesh) cell — the single entry point used by the
-trainer, the server, the dry-run, and the benchmarks.
+trainer, the server and the dry-run.
 
 ``input_specs`` returns ShapeDtypeStruct stand-ins for every model input
 (weak-type-correct, shardable, no device allocation) — the dry-run lowers

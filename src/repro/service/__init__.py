@@ -7,7 +7,7 @@ network front on them without perturbing their determinism:
   server   — asyncio TCP service coalescing concurrent RPCs into per-shard
              ``rpc_batch`` waves
   loadgen  — async load generator (10k–100k simulated clients) recording
-             RPC/s and tail latency for BENCH_rpc.json
+             RPC/s and tail latency; ``tests/test_service.py`` drives it
 """
 from .loadgen import LoadReport, run_load
 from .protocol import (

@@ -31,12 +31,8 @@ flip. The residual wrong-accepts in the defended goldens are wins
 day of work in the initial placement burst, long before any validation
 completes); a reactive defense cannot reach those, and the bound is
 pinned so a regression in either direction is loud.
-
-The per-scenario reports are dumped to ``benchmarks/SCENARIO_report.json``
-for the CI artifact.
 """
 import json
-import os
 
 import pytest
 
@@ -56,24 +52,10 @@ from repro.core import (
 from repro.core.scenarios import DAY, HOUR, SYBIL_ID_BASE, generate_population
 from repro.data import toggles_to_intervals
 
-REPORT_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "benchmarks",
-    "SCENARIO_report.json",
-)
 
-_REPORTS = []
-
-
-@pytest.fixture(scope="module", autouse=True)
-def _report_sink():
-    """Collect every scenario's golden-bound report; dump the artifact."""
-    yield _REPORTS
-    if _REPORTS:
-        os.makedirs(os.path.dirname(REPORT_PATH), exist_ok=True)
-        with open(REPORT_PATH, "w") as f:
-            json.dump({"scenarios": sorted(_REPORTS, key=lambda r: r["name"])},
-                      f, indent=2)
+def _assert_report_is_json(result):
+    """``ScenarioResult.report()`` is plain JSON data named after its spec."""
+    assert json.loads(json.dumps(result.report()))["name"] == result.spec.name
 
 
 # ---------------------------------------------------------------------------
@@ -317,6 +299,21 @@ def _check_clique_half_fleet_defended(r):
     assert d["quota_denials"] + d["clique_deferrals"] > 0
 
 
+@scenario(ScenarioSpec(name="honest_fleet_defended", seed=12, n_hosts=1000,
+                       n_jobs=300, horizon=0.5 * DAY, est_hours=0.05,
+                       availability=0.9, defense=DefensePolicy()))
+def _check_honest_fleet_defended(r):
+    """1,000 honest hosts with the whole defense stack on: every job
+    drains and nothing clusters, so the defense costs no liveness at fleet
+    scale (the small-fleet corners are test_defense_never_deadlocks_*)."""
+    counts = r.server.counts()
+    assert counts["jobs_success"] == 300
+    assert counts["jobs_failure"] == 0
+    assert counts["instances_unsent"] == 0
+    assert r.metrics.wrong_accepted == 0
+    assert r.report()["defense"]["n_clusters"] == 0
+
+
 @scenario(ScenarioSpec(name="clique_small_fleet", seed=2, n_hosts=6,
                        clique=Clique(size=3), n_jobs=40))
 def _check_clique_small_fleet(r):
@@ -487,7 +484,8 @@ for _seed in (7, 11):
 def test_scenario_matrix(name):
     spec, check = SCENARIOS[name]
     result = run_parity(spec)
-    _REPORTS.append(result.report())
+    assert len(result.population) == spec.n_hosts
+    _assert_report_is_json(result)
     check(result)
 
 
@@ -501,8 +499,8 @@ def test_adaptive_vs_plain_replication():
                 waves=12)
     plain = run_parity(ScenarioSpec(name="waves_plain", **base))
     adaptive = run_parity(ScenarioSpec(name="waves_adaptive", adaptive=True, **base))
-    _REPORTS.append(plain.report())
-    _REPORTS.append(adaptive.report())
+    _assert_report_is_json(plain)
+    _assert_report_is_json(adaptive)
     assert plain.metrics.replication_overhead >= 2.0
     assert adaptive.metrics.replication_overhead < plain.metrics.replication_overhead
     assert adaptive.metrics.replication_overhead < 1.9
@@ -807,7 +805,7 @@ def test_adversarial_10k_hosts():
         availability=0.9,
     )
     r = run_spec(spec, epoch=60.0)
-    _REPORTS.append(r.report())
+    _assert_report_is_json(r)
     counts = r.server.counts()
     assert counts["jobs_success"] >= 2900
     assert r.metrics.error_rate <= 0.01  # 5% clique: quorum holds at scale
