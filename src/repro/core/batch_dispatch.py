@@ -97,9 +97,9 @@ class BatchDispatchEngine:
         # unchanged.
         self.shard_map = shard_map
         self.shard = shard
-        # execution backend for the dense mask/score passes; "jax" routes
-        # them through core.jax_backend's staged jits (bit-identical to
-        # the NumPy path — 4th parity axis), sparse tails stay host-side
+        # execution backend for the dense score pass; "jax" runs it as one
+        # core.jax_backend launch a request (bit-identical to the NumPy
+        # path — 4th parity axis); the masks and sparse tails stay host-side
         self.backend = jax_backend.resolve_backend(backend)
         # cache-content generation this snapshot was built at; the
         # scheduler's persistent-dispatch path rebuilds when it trails
@@ -259,10 +259,7 @@ class BatchDispatchEngine:
         # rotated scan order, then first eligible slot per job (the scalar
         # scan's seen_jobs dedupe keeps the first valid slot it encounters)
         rot = np.arange(start, start + n) % n
-        if self.backend == "jax":
-            elig = jax_backend.dispatch_elig(self.valid, self.target, start, host.id)
-        else:
-            elig = self.valid[rot] & ((self.target[rot] < 0) | (self.target[rot] == host.id))
+        elig = self.valid[rot] & ((self.target[rot] < 0) | (self.target[rot] == host.id))
         pos = rot[elig]
         if pos.size == 0:
             return None
@@ -342,11 +339,8 @@ class BatchDispatchEngine:
         kvec_all = kw_val[self.kw_idx[reps]]
         kok = kw_ok[self.kw_idx[reps]]
 
-        if self.backend == "jax":
-            mask = jax_backend.dispatch_group_mask(g_ok[inv], hr_rep, host_hr_rep, kok)
-        else:
-            hr_ok = (hr_rep == -1) | (hr_rep == host_hr_rep)
-            mask = g_ok[inv] & hr_ok & kok
+        hr_ok = (hr_rep == -1) | (hr_rep == host_hr_rep)
+        mask = g_ok[inv] & hr_ok & kok
         if not mask.any():
             return None
         r = reps[mask]
@@ -363,8 +357,8 @@ class BatchDispatchEngine:
         avail = (res.availability if res else 1.0) * host.on_fraction
 
         if self.backend == "jax":
-            # dense base score + runtime estimates on device; the staged
-            # jits reproduce the NumPy accumulation order bit-for-bit
+            # dense base score + runtime estimates in one launch; it sums
+            # the host-rounded products in the NumPy accumulation order
             scores, est, scaled = jax_backend.dispatch_scores(
                 kvec_all[mask], bal_r, self.prio[r], self.skips[r],
                 self.est_flop[r], pf_r, avail,

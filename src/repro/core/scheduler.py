@@ -292,7 +292,7 @@ class Scheduler:
     # O(slots²) reference path as the oracle.
     vector_dispatch: bool = False
     # execution backend handed to BatchDispatchEngine ("numpy" | "jax");
-    # "jax" runs the dense mask/score passes as staged jits, bit-identical
+    # "jax" runs the dense score pass as one launch a request, bit-identical
     # to the NumPy engine (4th parity axis in core/scenarios.run_parity)
     engine_backend: str = "numpy"
     # defense layer (§3.4 work-spreading / HR census / host punishment);

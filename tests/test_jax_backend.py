@@ -35,7 +35,7 @@ try:  # optional dep: see requirements-dev.txt
 except ImportError:
     HAVE_HYPOTHESIS = False
 
-from repro.core import BatchClientEngine, ResourceType, jax_backend
+from repro.core import BatchClientEngine, BatchDispatchEngine, ResourceType, jax_backend
 from repro.core.client import (
     Client,
     ClientJob,
@@ -53,9 +53,11 @@ from repro.core.jax_backend import (
     resolve_backend,
 )
 from repro.core.scheduler import W_BALANCE, W_KEYWORD, W_PRIORITY, W_SKIPPED
+from repro.core.shard import ShardMap
 from repro.core.world import HostArrays
 from repro.kernels.quorum_compare.ref import quorum_compare_ref
 from test_batch_client import _assert_wrr_equal, make_clients
+from test_batch_dispatch import _make_requests, _make_server
 
 CPU = ResourceType.CPU
 
@@ -87,9 +89,14 @@ def test_resolve_backend():
 # ---------------------------------------------------------------------------
 
 
-def _prop_dispatch_scores(seed):
+def _check_dispatch_scores(seed, no_allocator_no_keywords):
     """Device score/est/scaled == the engine's NumPy branch, bit for bit
-    (same accumulation order; sparse-division-by-positive-pf pattern)."""
+    (same accumulation order; sparse-division-by-positive-pf pattern).
+
+    ``no_allocator_no_keywords`` passes ``bal=None`` with every keyword
+    term zero (signed zeros among them, and zero priorities and skips on
+    some lanes), so the kernel's added 0.0 balance row meets sums of
+    zeros."""
     rs = np.random.RandomState(seed)
     n = int(rs.randint(1, 65))
     kvec = rs.rand(n) < 0.5
@@ -99,6 +106,12 @@ def _prop_dispatch_scores(seed):
     flop = rs.uniform(1e9, 1e14, n)
     pf = np.where(rs.rand(n) < 0.2, 0.0, rs.uniform(1e8, 1e11, n))
     avail = float(rs.choice([0.0, 0.35, 1.0]))
+    if no_allocator_no_keywords:
+        bal = None
+        kvec = np.where(rs.rand(n) < 0.5, 0.0, -0.0)
+        zero = rs.rand(n) < 0.3
+        prio[zero] = np.where(rs.rand(int(zero.sum())) < 0.5, 0.0, -0.0)
+        skips[zero] = 0.0
 
     # inline replica of BatchDispatchEngine.candidate_rows' numpy branch
     scores = W_KEYWORD * kvec
@@ -118,9 +131,17 @@ def _prop_dispatch_scores(seed):
         kvec, bal, prio, skips, flop, pf, avail,
         (W_KEYWORD, W_BALANCE, W_PRIORITY, W_SKIPPED),
     )
-    assert np.array_equal(js, scores)
-    assert np.array_equal(je, est)
-    assert np.array_equal(jx, scaled)
+    for got, want in ((js, scores), (je, est), (jx, scaled)):
+        assert got.dtype == want.dtype
+        assert got.tobytes() == want.tobytes()
+
+
+def _prop_dispatch_scores(seed):
+    _check_dispatch_scores(seed, no_allocator_no_keywords=False)
+
+
+def _prop_dispatch_scores_no_allocator(seed):
+    _check_dispatch_scores(seed, no_allocator_no_keywords=True)
 
 
 def _prop_dispatch_elig(seed):
@@ -143,6 +164,11 @@ def test_dispatch_scores_matches_numpy(seed):
     _prop_dispatch_scores(seed)
 
 
+@pytest.mark.parametrize("seed", range(8))
+def test_dispatch_scores_without_allocator_or_keywords(seed):
+    _prop_dispatch_scores_no_allocator(seed)
+
+
 @pytest.mark.parametrize("seed", range(20))
 def test_dispatch_elig_matches_numpy(seed):
     _prop_dispatch_elig(seed)
@@ -150,6 +176,108 @@ def test_dispatch_elig_matches_numpy(seed):
 
 test_dispatch_scores_hypothesis = hyp(_prop_dispatch_scores, max_examples=60)
 test_dispatch_elig_hypothesis = hyp(_prop_dispatch_elig, max_examples=40)
+test_dispatch_scores_without_allocator_hypothesis = hyp(
+    _prop_dispatch_scores_no_allocator, max_examples=30)
+
+
+# ---------------------------------------------------------------------------
+# dispatch engine: one launch a scored request, rows identical to NumPy's
+# ---------------------------------------------------------------------------
+
+
+def _sharded_engines(seed, backend):
+    """A 1,024-slot cache over 4 shards, after one round of RPCs has set
+    HR-class and homogeneous-app-version locks; the server's allocator
+    gives every pass a balance term. Returns (scheduler, hosts, engines)."""
+    server, hosts = _make_server(seed, n_jobs=900, cache_size=1024)
+    server.rpc_batch(_make_requests(hosts, seed), 10.0)
+    shards = ShardMap(n_shards=4, cache_size=1024)
+    engines = [
+        BatchDispatchEngine(server.store, server.feeder, backend=backend,
+                            shard_map=shards, shard=k)
+        for k in range(4)
+    ]
+    sched = server.schedulers[0]
+    assert sched.allocator is not None
+    return sched, hosts, engines
+
+
+def _passes(hosts, engines, seed):
+    """Every (engine, request) candidate pass, in a fixed order."""
+    for k, engine in enumerate(engines):
+        for i, (host, req) in enumerate(zip(hosts, _make_requests(hosts, seed + 7))):
+            start = (37 * i + 101 * k) % engine.n
+            yield engine, host, req, start
+
+
+def _count_calls(monkeypatch, *names):
+    """Wrap the named dispatch kernels; returns the list each call of any
+    of them appends its name to."""
+    calls = []
+    for name in names:
+        def counted(*args, _name=name, _kernel=getattr(jax_backend, name)):
+            calls.append(_name)
+            return _kernel(*args)
+
+        monkeypatch.setattr(jax_backend, name, counted)
+    return calls
+
+
+def test_jax_candidate_rows_make_one_launch_a_scored_pass(monkeypatch):
+    """Each candidate pass that scores makes one score launch and no mask
+    launch; a pass that finds no candidate makes none."""
+    sched, hosts, engines = _sharded_engines(5, "jax")
+    scores = _count_calls(monkeypatch, "_k_score_est")
+    masks = _count_calls(monkeypatch, "_k_elig", "_k_group_mask")
+    scored = 0
+    for engine, host, req, start in _passes(hosts, engines, 5):
+        before = len(scores)
+        rows = engine.candidate_rows(sched, host, req, CPU, start, 20.0)
+        assert len(scores) - before == (0 if rows is None else 1)
+        scored += rows is not None
+    assert scored > 0
+    assert masks == []
+
+    # a shard with no eligible slot launches nothing
+    empty = engines[0]
+    empty.valid[:] = False
+    before = len(scores)
+    assert empty.candidate_rows(sched, hosts[0], _make_requests(hosts, 5)[0],
+                                CPU, 0, 20.0) is None
+    assert len(scores) == before
+
+
+def _rows_sig(rows):
+    if rows is None:
+        return None
+    pos, gidx, scores, est, scaled, choices, disk, delay = rows
+    arrays = tuple((a.dtype.str, a.tobytes())
+                   for a in (pos, gidx, scores, est, scaled, disk, delay))
+    groups = tuple(
+        (c.version.id if c.version is not None else None,
+         c.usage, c.pf, c.size_q)
+        for c in choices
+    )
+    return arrays, groups
+
+
+def test_jax_candidate_rows_match_numpy_on_sharded_cache():
+    """Twin servers: a pinned, HR-locked, hav-locked 1,024-slot cache over
+    4 shards with an allocator; each pass's rows are the same bits."""
+    sched_n, hosts_n, eng_n = _sharded_engines(6, "numpy")
+    sched_j, hosts_j, eng_j = _sharded_engines(6, "jax")
+    assert any((e.pin != -1).any() for e in eng_j)
+    assert any((e.hav != -1).any() for e in eng_j)
+    assert any((e.hr_id != -1).any() for e in eng_j)
+    scored = 0
+    for (en, hn, rn, st), (ej, hj, rj, _) in zip(
+        _passes(hosts_n, eng_n, 6), _passes(hosts_j, eng_j, 6)
+    ):
+        want = _rows_sig(en.candidate_rows(sched_n, hn, rn, CPU, st, 20.0))
+        got = _rows_sig(ej.candidate_rows(sched_j, hj, rj, CPU, st, 20.0))
+        assert got == want
+        scored += want is not None
+    assert scored > 0
 
 
 # ---------------------------------------------------------------------------

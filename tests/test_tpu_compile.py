@@ -106,17 +106,12 @@ def test_world_completed_fits_one_chip(spec):
     _fits(compiled)
 
 
-def test_dispatch_score_terms_compile(spec):
-    v = spec((CACHE,), jnp.float64)
-    w = spec((), jnp.float64)
-    compiled = jax_backend._k_score_terms.lower(v, v, v, v, w, w, w, w).compile()
-    _fits(compiled)
-
-
-def test_dispatch_est_scaled_compile(spec):
-    v = spec((CACHE,), jnp.float64)
-    compiled = jax_backend._k_est_scaled.lower(v, v, spec((), jnp.float64)).compile()
-    _fits(compiled)
+@pytest.mark.parametrize("bucket", [8, 16, CACHE])
+def test_dispatch_score_pass_compiles(spec, bucket):
+    # the one launch a scored request makes: four host-rounded terms,
+    # flop, pf and avail packed in one f64 array, padded to the bucket
+    packed = spec((7, bucket), jnp.float64)
+    _fits(jax_backend._k_score_est.lower(packed).compile())
 
 
 @pytest.mark.parametrize("rows", [2, 3])
